@@ -93,6 +93,9 @@ def subprocess_gate() -> None:
 
 
 def main() -> None:
+    # a CPU gate: pin this process and the serve child it starts to the
+    # CPU, so neither takes (or waits on) an accelerator the other holds
+    os.environ["JAX_PLATFORMS"] = "cpu"
     in_process()
     subprocess_gate()
     print("decode smoke OK")

@@ -18,6 +18,7 @@ Run from the repo root:  PYTHONPATH=src python scripts/edit_smoke.py
 """
 from __future__ import annotations
 
+import os
 import re
 import subprocess
 import sys
@@ -92,6 +93,9 @@ def driver_edit_traffic() -> None:
 
 
 def main() -> int:
+    # a CPU gate: pin this process and the serve child it starts to the
+    # CPU, so neither takes (or waits on) an accelerator the other holds
+    os.environ["JAX_PLATFORMS"] = "cpu"
     in_process_parity()
     driver_edit_traffic()
     return 0

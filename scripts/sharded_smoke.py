@@ -163,6 +163,9 @@ def subprocess_launch() -> None:
 
 
 def main() -> int:
+    # a CPU gate: pin this process and the serve child it starts to the
+    # CPU, so neither takes (or waits on) an accelerator the other holds
+    os.environ["JAX_PLATFORMS"] = "cpu"
     in_process()
     subprocess_launch()
     return 0

@@ -101,6 +101,43 @@ def reduced(cfg: ArchConfig) -> ArchConfig:
     return dataclasses.replace(cfg, name=cfg.name + "-smoke", **kw)
 
 
+def _structural_period(cfg: ArchConfig) -> int:
+    """Layers in one repeat of the stack's layer pattern (1 for a plain
+    dense or every-layer-MoE decoder)."""
+    if cfg.hybrid_period:
+        return cfg.hybrid_period
+    if cfg.cross_attn_every:
+        return cfg.cross_attn_every
+    if cfg.moe is not None and cfg.moe.every > 1:
+        return cfg.moe.every
+    return 1
+
+
+def depth_cut(cfg: ArchConfig, n_layers: int) -> ArchConfig:
+    """Published widths at reduced depth: only ``n_layers`` changes.
+
+    Every width (d_model, heads, KV heads, head_dim, d_ff, vocabulary,
+    expert shapes) and both dtypes stay as published, so per-layer compute,
+    KV bytes per token and kernel shapes are the real ones; only the number
+    of stacked layers shrinks to what one chip holds.  The cut must keep
+    whole structural periods (a dense decoder's period is 1) and any
+    leading dense layers of an MoE stack.  The name records the cut, e.g.
+    ``deepseek-67b-4L`` for 4 of 95 layers.
+    """
+    period = _structural_period(cfg)
+    lead = cfg.moe.first_dense_layers if cfg.moe is not None else 0
+    if n_layers < 1 or n_layers > cfg.n_layers or n_layers % period:
+        raise ValueError(
+            f"{cfg.name}: a depth cut keeps whole periods of {period} "
+            f"layers, between {period} and {cfg.n_layers}; got {n_layers}")
+    if n_layers <= lead:
+        raise ValueError(
+            f"{cfg.name}: {n_layers} layers would keep only the {lead} "
+            f"leading dense layers and none of the MoE stack")
+    return dataclasses.replace(cfg, name=f"{cfg.name}-{n_layers}L",
+                               n_layers=n_layers)
+
+
 __all__ = [
     "ARCHS",
     "ArchConfig",
@@ -110,6 +147,7 @@ __all__ = [
     "SSMConfig",
     "ShapeSpec",
     "cells_for",
+    "depth_cut",
     "get_config",
     "list_archs",
     "reduced",

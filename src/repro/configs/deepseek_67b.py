@@ -1,4 +1,11 @@
-"""DeepSeek-67B — dense Llama-arch decoder [arXiv:2401.02954; hf]."""
+"""DeepSeek-67B — dense Llama-arch decoder [arXiv:2401.02954; hf].
+
+Every layer is the same GQA-attention + SwiGLU block, so the structural
+period is 1 and ``depth_cut(CONFIG, n)`` keeps a whole model at any depth:
+all widths below stay as published and only ``n_layers`` shrinks.  At 4
+layers the bf16 weights (embedding and head included) come to ≈ 8.9 GB,
+which one 16 GB TPU v5e chip holds with room for KV cache.
+"""
 from .base import ArchConfig
 
 CONFIG = ArchConfig(

@@ -27,7 +27,6 @@ from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.train.optim import Optimizer, clip_by_global_norm, make_optimizer, warmup_cosine
 
-from .compat import shard_map
 from .compression import compressed_psum, ef_state_like
 
 
@@ -76,7 +75,7 @@ def make_multipod_train_step(
     # pod axis manual; data/model remain auto so the inner step lowers with
     # the same shardings as single-pod. params/opt/ef are pod-replicated;
     # the batch's leading dim is split across pods.
-    step_fn = shard_map(
+    step_fn = jax.shard_map(
         per_pod_step,
         mesh=mesh,
         in_specs=(P(), P(), P(), P("pod"), P()),

@@ -19,58 +19,75 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+_LANES = 128
+#: full f32 MXU passes: by default Mosaic rounds f32 operands to bf16 for
+#: a single pass, which would cost the statistics ~3 significant digits
+_F32 = jax.lax.Precision.HIGHEST
+
 
 def _kernel(x_ref, y_ref, m_ref, w_ref, b_ref, *, lam: float, lr: float, batch: int):
-    l, d = x_ref.shape[1], x_ref.shape[2]
-    steps = l // batch
-
-    x_all = x_ref[0]            # (l, d) — VMEM resident
-    y_all = y_ref[0]            # (l,)
-    m_all = m_ref[0]            # (l,)
+    d = x_ref.shape[2]
+    steps = y_ref.shape[1]
+    contract_d = (((1,), (1,)), ((), ()))        # (1, d) · (batch, d)ᵀ
+    contract_b = (((1,), (0,)), ((), ()))        # (1, batch) · (batch, d)
 
     def body(t, carry):
-        w, b = carry
-        start = t * batch
-        xb = jax.lax.dynamic_slice_in_dim(x_all, start, batch, 0)
-        yb = jax.lax.dynamic_slice_in_dim(y_all, start, batch, 0)
-        mb = jax.lax.dynamic_slice_in_dim(m_all, start, batch, 0)
-        z = jnp.dot(xb, w, preferred_element_type=jnp.float32) + b
+        w, b = carry                             # (1, d), (1, 1)
+        start = pl.multiple_of(t * batch, batch)
+        xb = x_ref[0, pl.ds(start, batch), :]    # (batch, d) — VMEM resident
+        yb = y_ref[0, pl.ds(t, 1), :]            # (1, batch): minibatch t's row
+        mb = m_ref[0, pl.ds(t, 1), :]
+        z = jax.lax.dot_general(w, xb, contract_d, precision=_F32,
+                                preferred_element_type=jnp.float32) + b
         g = (jax.nn.sigmoid(z) - yb) * mb
-        denom = jnp.maximum(mb.sum(), 1.0)
-        step = lr / jnp.sqrt(t.astype(jnp.float32) + 1.0)
-        gw = jnp.dot(xb.T, g, preferred_element_type=jnp.float32) / denom + 2.0 * lam * w
-        gb = g.sum() / denom
+        denom = jnp.maximum(mb.sum(axis=1, keepdims=True), 1.0)
+        tf = jnp.full((1, 1), t, jnp.int32).astype(jnp.float32)
+        step = lr / jnp.sqrt(tf + 1.0)
+        gw = jax.lax.dot_general(g, xb, contract_b, precision=_F32,
+                                 preferred_element_type=jnp.float32) / denom
+        gw = gw + 2.0 * lam * w
+        gb = g.sum(axis=1, keepdims=True) / denom
         return (w - step * gw, b - step * gb)
 
-    w0 = jnp.zeros((d,), jnp.float32)
-    w, b = jax.lax.fori_loop(0, steps, body, (w0, jnp.float32(0.0)))
+    w0 = jnp.zeros((1, d), jnp.float32)
+    b0 = jnp.zeros((1, 1), jnp.float32)
+    w, b = jax.lax.fori_loop(0, steps, body, (w0, b0))
     w_ref[0] = w
-    b_ref[0, 0] = b
+    b_ref[0] = jnp.broadcast_to(b, b_ref.shape[1:])
 
 
 @functools.partial(
     jax.jit, static_argnames=("lam", "lr", "batch", "interpret")
 )
 def sgd_chunks(x, y, mask, *, lam: float, lr: float, batch: int, interpret: bool = False):
-    """Run one SGD epoch per chunk.  ``x`` (p, l, d); returns (p, d), (p, 1)."""
+    """Run one SGD epoch per chunk.
+
+    ``x`` (p, l, d); ``y``/``mask`` (p, l // batch, batch) — one row per
+    minibatch, so the kernel reads minibatch t as a row slice.  Returns
+    weights (p, 1, d) and bias (p, 1, 128), the bias broadcast across its
+    lane row.  Every block's trailing two dims equal the array's, which is
+    the TPU tiling rule for blocks narrower than (8, 128).
+    """
     p, l, d = x.shape
     assert l % batch == 0 and d % 128 == 0, (l, d, batch)
+    assert y.shape == mask.shape == (p, l // batch, batch), (y.shape, mask.shape)
+    steps = l // batch
     kern = functools.partial(_kernel, lam=lam, lr=lr, batch=batch)
     return pl.pallas_call(
         kern,
         grid=(p,),
         in_specs=[
             pl.BlockSpec((1, l, d), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, l), lambda i: (i, 0)),
-            pl.BlockSpec((1, l), lambda i: (i, 0)),
+            pl.BlockSpec((1, steps, batch), lambda i: (i, 0, 0)),
+            pl.BlockSpec((1, steps, batch), lambda i: (i, 0, 0)),
         ],
         out_specs=[
-            pl.BlockSpec((1, d), lambda i: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
+            pl.BlockSpec((1, 1, d), lambda i: (i, 0, 0)),
+            pl.BlockSpec((1, 1, _LANES), lambda i: (i, 0, 0)),
         ],
         out_shape=[
-            jax.ShapeDtypeStruct((p, d), jnp.float32),
-            jax.ShapeDtypeStruct((p, 1), jnp.float32),
+            jax.ShapeDtypeStruct((p, 1, d), jnp.float32),
+            jax.ShapeDtypeStruct((p, 1, _LANES), jnp.float32),
         ],
         interpret=interpret,
     )(x, y, mask)

@@ -35,5 +35,7 @@ def logreg_sgd_batched(X, y, *, lam: float = 1e-3, lr: float = 0.5, batch: int =
     Xp = pad_axis(pad_axis(X, 2, dp), 1, lp)
     yp = pad_axis(y, 1, lp)
     mp = pad_axis(mask, 1, lp)
-    w, b = sgd_chunks(Xp, yp, mp, lam=lam, lr=lr, batch=batch, interpret=use_interpret())
-    return w[:, :d], b
+    rows = (p, lp // batch, batch)              # one row per minibatch
+    w, b = sgd_chunks(Xp, yp.reshape(rows), mp.reshape(rows), lam=lam, lr=lr,
+                      batch=batch, interpret=use_interpret())
+    return w[:, 0, :d], b[:, 0, :1]

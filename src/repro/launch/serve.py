@@ -10,6 +10,13 @@ Multi-session batched serving (shared segment store, continuous batching):
   PYTHONPATH=src python -m repro.launch.serve --arch deepseek-67b --reduced \
       --doc-len 1024 --sessions 6 --shared-docs 2 --requests 2 --new-tokens 8
 
+Published widths on one chip: ``--layers N`` keeps every width and dtype
+of the arch and cuts only its depth (``configs.depth_cut``); deepseek-67b
+at 4 layers is ≈ 8.9 GB of bf16 weights:
+
+  PYTHONPATH=src python -m repro.launch.serve --arch deepseek-67b --layers 4 \
+      --doc-len 2048 --sessions 4 --requests 2 --new-tokens 16
+
 Warm restarts: ``--store-dir`` makes the segment store durable — on
 startup an existing snapshot is reloaded (the replayed traffic is served
 from the warm segments instead of re-prefilled), ``--snapshot-every N``
@@ -57,10 +64,12 @@ from every tier:
 from __future__ import annotations
 
 import argparse
-from pathlib import Path
+import time
 
 import jax
 import numpy as np
+
+from repro.launch.compile_cache import use_compile_cache
 
 
 def _tier_kwargs(args) -> dict:
@@ -241,7 +250,7 @@ def _extras(cfg):
     return extras
 
 
-def run_single(args, cfg, model, params, rng) -> None:
+def run_single(args, cfg, model, params, rng):
     from repro.serve.engine import ServeEngine
 
     doc = rng.integers(0, cfg.vocab_size, args.doc_len).astype(np.int32)
@@ -275,9 +284,10 @@ def run_single(args, cfg, model, params, rng) -> None:
           f"({eng.store.nbytes()/1e6:.1f} MB)")
     _print_tier_report(eng.store, args)
     _print_shard_report(eng.store)
+    return eng
 
 
-def run_multi(args, cfg, model, params, rng) -> None:
+def run_multi(args, cfg, model, params, rng):
     from repro.serve.session import SessionManager
 
     n_shared = min(max(args.shared_docs, 0), args.sessions)
@@ -301,8 +311,6 @@ def run_multi(args, cfg, model, params, rng) -> None:
     for i in range(args.sessions):
         doc = shared_doc if i < n_shared else unique_docs[i - n_shared]
         sids.append(mgr.add_session(doc, extras=dict(extras)))
-
-    import time
 
     edit_reused = edit_rebuilt = 0
     t0 = time.perf_counter()
@@ -351,6 +359,8 @@ def run_multi(args, cfg, model, params, rng) -> None:
           f"admitted, {mgr.sched.decode_rejects} rejected")
     rep = mgr.report()   # guarded: finite even on an idle/zero-traffic run
     packing = "merged ragged" if mgr.merge_decode_packs else "capacity-split"
+    print(f"  attention routes: decode {mgr.decode_mode}, "
+          f"extend {mgr.extend_mode}")
     print(f"  decode packs ({packing}, {mgr.decode_mode} attention): "
           f"padded occupancy {rep['decode_padded_frac']:.1%} "
           f"({rep['decode_valid_tokens']} valid / "
@@ -376,12 +386,32 @@ def run_multi(args, cfg, model, params, rng) -> None:
     if args.store_dir and st.last_save:
         print(f"  snapshot: {st.last_save['written']} entries written, "
               f"{st.last_save['reused']} reused from the previous snapshot")
+    return mgr
 
 
-def main() -> None:
+def _check_saves(store) -> None:
+    """Fail the run if any background snapshot failed.
+
+    The writer thread records failures in ``save_errors`` instead of
+    raising into the serving loop; this is where they surface, after the
+    final save, so a run whose snapshots were lost never exits 0.
+    """
+    shards = [store, *getattr(store, "remotes", ())]
+    errors = [e for st in shards for e in st.save_errors]
+    if errors:
+        raise SystemExit(f"{len(errors)} background snapshot save(s) "
+                         f"failed; first: {errors[0]!r}")
+
+
+def main(argv=None):
+    """Parse ``argv`` (default: the command line), serve, and return the
+    ``SessionManager`` (``--sessions`` > 1) or ``ServeEngine`` that ran."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true")
+    ap.add_argument("--layers", type=int, default=0,
+                    help="keep every published width and dtype but only N "
+                         "layers (whole structural periods; 0 = all)")
     ap.add_argument("--doc-len", type=int, default=1024)
     ap.add_argument("--requests", type=int, default=6)
     ap.add_argument("--new-tokens", type=int, default=8)
@@ -487,21 +517,32 @@ def main() -> None:
                     help="after the final snapshot: rewrite the snapshot "
                          "dir compactly (drops stranded files and "
                          "hard-link chains from older generations)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
 
-    from repro.configs import get_config, reduced
+    from repro.configs import depth_cut, get_config, reduced
     from repro.models.lm import LM
 
+    use_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = reduced(cfg)
+    if args.layers:
+        cfg = depth_cut(cfg, args.layers)
     model = LM(cfg)
-    params = model.init(jax.random.PRNGKey(args.seed))
+    t0 = time.perf_counter()
+    params = jax.block_until_ready(model.init(jax.random.PRNGKey(args.seed)))
+    leaves = jax.tree.leaves(params)
+    dev = jax.devices()[0]
+    peak = (dev.memory_stats() or {}).get("peak_bytes_in_use")
+    print(f"model {cfg.name}: {sum(x.size for x in leaves) / 1e9:.2f}B params, "
+          f"{sum(x.nbytes for x in leaves) / 1e9:.2f} GB {cfg.param_dtype} on "
+          f"{dev.device_kind}, init {time.perf_counter() - t0:.1f} s"
+          + (f", device peak {peak / 1e9:.2f} GB" if peak else ""), flush=True)
     rng = np.random.default_rng(args.seed)
-    if args.sessions > 1:
-        run_multi(args, cfg, model, params, rng)
-    else:
-        run_single(args, cfg, model, params, rng)
+    run = run_multi if args.sessions > 1 else run_single
+    server = run(args, cfg, model, params, rng)
+    _check_saves(server.store)
+    return server
 
 
 if __name__ == "__main__":

@@ -66,7 +66,10 @@ def _init_leaf(spec: ParamSpec, key, dtype) -> jnp.ndarray:
     if spec.init == "ones":
         return jnp.ones(spec.shape, dtype)
     std = 0.02 * spec.scale if spec.init == "normal" else 0.006 * spec.scale
-    return (jax.random.normal(key, spec.shape, jnp.float32) * std).astype(dtype)
+    # drawn in the param dtype: an f32 draw of a bf16 leaf would hold a
+    # transient twice the leaf's size (2.9 GB for one stacked d_ff matrix
+    # of a published-width model) on a chip that is already mostly weights
+    return jax.random.normal(key, spec.shape, dtype) * jnp.asarray(std, dtype)
 
 
 def init_params(specs, key, dtype):

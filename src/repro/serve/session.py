@@ -57,7 +57,8 @@ import numpy as np
 from repro.core.cost import CostModel, serve_cost_model
 from repro.core.descriptors import Range
 from repro.core.optimizer import Plan
-from repro.kernels.common import bucket_len, decode_kernel_mode
+from repro.kernels.common import (bucket_len, decode_kernel_mode,
+                                  extend_kernel_mode)
 
 from .engine import PendingBuild, PrefixCacheBuilder, ServeStats
 from .kv_cache import (SEQ_KEYS, SegmentStore, _leaf_key, cache_len,
@@ -315,6 +316,9 @@ class SessionManager:
         # pre-kernel capacity-split grouping remains the default
         # (REPRO_DECODE_KERNEL=0 ⇒ behavior bit-identical to pre-kernel).
         self.decode_mode = decode_kernel_mode()
+        # how prefix builds run their suffix attention ('kernel' | 'jax');
+        # like decode_mode, read once here for the report
+        self.extend_mode = extend_kernel_mode()
         if merge_decode_packs is None:
             merge_decode_packs = self.decode_mode != "dense"
         self.merge_decode_packs = merge_decode_packs
