@@ -7,7 +7,6 @@ the optimizer (its cost is negligible — §6.4), executes the winning plan
 """
 from __future__ import annotations
 
-import time
 from dataclasses import dataclass, field
 from typing import Any, Literal, Optional
 
@@ -17,6 +16,7 @@ from .families import get_family
 from .optimizer import Plan, baseline_plan, shortest_plan
 from .planner import ExecResult, ExecTimings, execute
 from .store import ModelStore
+from .trace import span
 
 MaterializePolicy = Literal["never", "always", "chunks"]
 
@@ -56,52 +56,48 @@ class IncrementalAnalyticsEngine:
         # planning and victim selection price F(n)/C(M) identically
         self.store = store if store is not None else ModelStore(cost_model=self.cost)
         self.materialize: MaterializePolicy = materialize
-        self.stats = {"queries": 0, "reused": 0, "optimizer_s": 0.0}
 
     # ------------------------------------------------------------------
     def query(self, family_name: str, rng: Range, *, force_baseline: bool = False,
               **overrides: Any) -> QueryResult:
-        family = get_family(family_name)
-        params = {**family.defaults, **overrides}
-        if family_name in ("gaussian_nb", "multinomial_nb") and "n_classes" not in overrides:
-            params["n_classes"] = getattr(self.backend, "n_classes", params["n_classes"])
+        with span("repro.query", query=True):
+            family = get_family(family_name)
+            params = {**family.defaults, **overrides}
+            if family_name in ("gaussian_nb", "multinomial_nb") and "n_classes" not in overrides:
+                params["n_classes"] = getattr(self.backend, "n_classes", params["n_classes"])
 
-        base = baseline_plan(rng, self.cost)
-        plan = shortest_plan(
-            self.store.index(family_name),
-            rng,
-            self.cost,
-            self.store.model_bytes(family_name),
-            directed=not family.supports_delete,
-        )
-        self.stats["optimizer_s"] += plan.optimizer_seconds
+            with span("repro.plan") as sp:
+                base = baseline_plan(rng, self.cost)
+                plan = shortest_plan(
+                    self.store.index(family_name),
+                    rng,
+                    self.cost,
+                    self.store.model_bytes(family_name),
+                    directed=not family.supports_delete,
+                )
 
-        use_reuse = (plan.cost < base.cost) and not force_baseline
-        chosen = plan if use_reuse else base
-        if not use_reuse:
-            # keep the measured optimizer overhead attributed to the query
-            chosen.optimizer_seconds = plan.optimizer_seconds
+            use_reuse = (plan.cost < base.cost) and not force_baseline
+            chosen = plan if use_reuse else base
 
-        res = execute(
-            chosen, family, self.store, self.backend, params,
-            materialize_chunks=(self.materialize != "never"),
-        )
-        if self.materialize == "always" and family.supports_delete:
-            mid = self.store.put(family_name, rng, res.stats, meta={"query": True})
-            res.materialized_ids.append(mid)
+            res = execute(
+                chosen, family, self.store, self.backend, params,
+                materialize_chunks=(self.materialize != "never"),
+            )
+            res.timings.optimizer_s = sp.seconds
+            if self.materialize == "always" and family.supports_delete:
+                mid = self.store.put(family_name, rng, res.stats, meta={"query": True})
+                res.materialized_ids.append(mid)
 
-        self.stats["queries"] += 1
-        self.stats["reused"] += int(use_reuse and any(s.model_id for s in chosen.steps))
-        return QueryResult(
-            model=res.model,
-            stats=res.stats,
-            plan=chosen,
-            timings=res.timings,
-            used_reuse=use_reuse,
-            baseline_cost=base.cost,
-            plan_cost=plan.cost,
-            materialized_ids=res.materialized_ids,
-        )
+            return QueryResult(
+                model=res.model,
+                stats=res.stats,
+                plan=chosen,
+                timings=res.timings,
+                used_reuse=use_reuse,
+                baseline_cost=base.cost,
+                plan_cost=plan.cost,
+                materialized_ids=res.materialized_ids,
+            )
 
     # ------------------------------------------------------------------
     def baseline(self, family_name: str, rng: Range, **overrides: Any) -> QueryResult:
@@ -110,16 +106,14 @@ class IncrementalAnalyticsEngine:
         params = {**family.defaults, **overrides}
         if family_name in ("gaussian_nb", "multinomial_nb") and "n_classes" not in overrides:
             params["n_classes"] = getattr(self.backend, "n_classes", params["n_classes"])
-        timings = ExecTimings()
-        t0 = time.perf_counter()
-        X, y = self.backend.fetch(rng)
-        timings.io_s = time.perf_counter() - t0
-        t0 = time.perf_counter()
-        stats = family.compute_stats(X, y, params)
-        timings.compute_s = time.perf_counter() - t0
-        t0 = time.perf_counter()
-        model = family.solve(stats, params)
-        timings.merge_s = time.perf_counter() - t0
+        with span("repro.fetch") as io:
+            X, y = self.backend.fetch(rng)
+        with span("repro.stats") as compute:
+            stats = family.compute_stats(X, y, params)
+        with span("repro.solve") as solve:
+            model = family.solve(stats, params)
+        timings = ExecTimings(io_s=io.seconds, compute_s=compute.seconds,
+                              merge_s=solve.seconds)
         plan = baseline_plan(rng, self.cost)
         return QueryResult(
             model=model, stats=stats, plan=plan, timings=timings, used_reuse=False,
@@ -195,34 +189,29 @@ class IncrementalAnalyticsEngine:
 
         timings = ExecTimings()
         if action == "delta":
+            steps = [(r, +1) for r in add] + [(r, -1) for r in delete]
             new_stats = stats
-            for rng, sign in [(r, +1) for r in add] + [(r, -1) for r in delete]:
-                t0 = time.perf_counter()
-                X, y = self.backend.fetch(rng)
-                timings.io_s += time.perf_counter() - t0
-                t0 = time.perf_counter()
-                d = family.compute_stats(X, y, params)
-                timings.compute_s += time.perf_counter() - t0
-                t0 = time.perf_counter()
-                new_stats = new_stats + d if sign > 0 else new_stats - d
-                timings.merge_s += time.perf_counter() - t0
         else:
+            steps = [(r, +1) for r in new_cov]
             new_stats = None
-            for rng in new_cov:
-                t0 = time.perf_counter()
+        for rng, sign in steps:
+            with span("repro.fetch") as sp:
                 X, y = self.backend.fetch(rng)
-                timings.io_s += time.perf_counter() - t0
-                t0 = time.perf_counter()
+            timings.io_s += sp.seconds
+            with span("repro.stats") as sp:
                 d = family.compute_stats(X, y, params)
-                timings.compute_s += time.perf_counter() - t0
-                t0 = time.perf_counter()
-                new_stats = d if new_stats is None else new_stats + d
-                timings.merge_s += time.perf_counter() - t0
-            if new_stats is None:
-                raise ValueError("update would leave empty coverage")
-        t0 = time.perf_counter()
-        model = family.solve(new_stats, params)
-        timings.merge_s += time.perf_counter() - t0
+            timings.compute_s += sp.seconds
+            with span("repro.merge") as sp:
+                if new_stats is None:
+                    new_stats = d
+                else:
+                    new_stats = new_stats + d if sign > 0 else new_stats - d
+            timings.merge_s += sp.seconds
+        if new_stats is None:
+            raise ValueError("update would leave empty coverage")
+        with span("repro.solve") as sp:
+            model = family.solve(new_stats, params)
+        timings.merge_s += sp.seconds
 
         materialized: list[str] = []
         if (self.materialize == "always" and family.supports_delete

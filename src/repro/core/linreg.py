@@ -13,6 +13,7 @@ from dataclasses import dataclass
 
 import numpy as np
 
+from . import trace
 from .suffstats import LinRegStats
 
 
@@ -51,11 +52,10 @@ def compute_stats(X: np.ndarray, y: np.ndarray, *, backend: str = "numpy") -> Li
         from repro.kernels.linreg_stats import ops as k_ops
 
         A, B = k_ops.linreg_stats(np.asarray(X, np.float32), np.asarray(y, np.float32))
-        return LinRegStats(
-            n=np.asarray(float(X.shape[0]), np.float64),
-            A=np.asarray(A, np.float64),
-            B=np.asarray(B, np.float64),
-        )
+        with trace.span("repro.kernel.sync"):
+            trace.count("repro.device_reads", 2)
+            A, B = np.asarray(A, np.float64), np.asarray(B, np.float64)
+        return LinRegStats(n=np.asarray(float(X.shape[0]), np.float64), A=A, B=B)
     raise ValueError(f"unknown backend {backend!r}")
 
 

@@ -16,6 +16,7 @@ from dataclasses import dataclass
 
 import numpy as np
 
+from . import trace
 from .suffstats import LogRegMixtureStats
 
 
@@ -67,13 +68,11 @@ def sgd_pass(
     if backend == "pallas":
         from repro.kernels.logreg_sgd import ops as k_ops
 
-        return np.asarray(
-            k_ops.logreg_sgd(
-                np.asarray(X, np.float32), np.asarray(y, np.float32),
-                lam=lam, lr=lr, batch=batch,
-            ),
-            np.float64,
-        )
+        w = k_ops.logreg_sgd(np.asarray(X, np.float32), np.asarray(y, np.float32),
+                             lam=lam, lr=lr, batch=batch)
+        with trace.span("repro.kernel.sync"):
+            trace.count("repro.device_reads")
+            return np.asarray(w, np.float64)
     X = np.asarray(X, np.float64)
     y = np.asarray(y, np.float64)
     n, d = X.shape

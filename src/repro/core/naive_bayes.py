@@ -9,6 +9,7 @@ from dataclasses import dataclass
 
 import numpy as np
 
+from . import trace
 from .suffstats import GaussianNBStats, MultinomialNBStats
 
 _VAR_FLOOR = 1e-9
@@ -61,11 +62,10 @@ def compute_gaussian_stats(X, y, n_classes: int, *, backend: str = "numpy") -> G
         counts, S, SS = k_ops.nb_stats(
             np.asarray(X, np.float32), np.asarray(y, np.int32), n_classes
         )
-        return GaussianNBStats(
-            counts=np.asarray(counts, np.float64),
-            S=np.asarray(S, np.float64),
-            SS=np.asarray(SS, np.float64),
-        )
+        with trace.span("repro.kernel.sync"):
+            trace.count("repro.device_reads", 3)
+            counts, S, SS = (np.asarray(a, np.float64) for a in (counts, S, SS))
+        return GaussianNBStats(counts=counts, S=S, SS=SS)
     raise ValueError(f"unknown backend {backend!r}")
 
 

@@ -7,7 +7,6 @@ for future queries — exactly the paper's warm-up behaviour.
 """
 from __future__ import annotations
 
-import time
 from dataclasses import dataclass, field
 from typing import Any, Optional
 
@@ -18,16 +17,17 @@ from .descriptors import DescriptorIndex, Range, covered_size
 from .families import ModelFamily
 from .optimizer import Plan
 from .store import ModelStore
+from .trace import span
 
 
 @dataclass
 class ExecTimings:
-    """Fig 5 decomposition."""
+    """Fig 5 decomposition, summed from the spans of ``repro.core.trace``."""
 
-    optimizer_s: float = 0.0
-    io_s: float = 0.0        # base-data fetches + model loads
-    compute_s: float = 0.0   # stats passes / chunk SGD
-    merge_s: float = 0.0     # stat combine/uncombine + solve
+    optimizer_s: float = 0.0  # repro.plan
+    io_s: float = 0.0        # repro.fetch + repro.load: base data, stored models
+    compute_s: float = 0.0   # repro.stats: stats passes / chunk SGD
+    merge_s: float = 0.0     # repro.merge + repro.solve
 
     @property
     def total_s(self) -> float:
@@ -52,7 +52,7 @@ def execute(
     *,
     materialize_chunks: bool = True,
 ) -> ExecResult:
-    timings = ExecTimings(optimizer_s=plan.optimizer_seconds)
+    timings = ExecTimings()
     pos: Optional[Any] = None
     neg: Optional[Any] = None
     new_ids: list[str] = []
@@ -66,39 +66,41 @@ def execute(
     with store.pinned(plan.models_used):
         for step in plan.steps:
             if step.model_id is not None:
-                t0 = time.perf_counter()
-                stats = store.get(step.model_id).stats
-                timings.io_s += time.perf_counter() - t0
+                with span("repro.load") as sp:
+                    stats = store.get(step.model_id).stats
+                timings.io_s += sp.seconds
             else:
-                t0 = time.perf_counter()
-                X, y = backend.fetch(step.rng)
-                timings.io_s += time.perf_counter() - t0
-                t0 = time.perf_counter()
-                if monoid and materialize_chunks:
-                    # fit chunk-by-chunk and materialize each chunk (§4)
-                    stats = None
-                    for s in range(0, step.rng.size, chunk_size):
-                        sub = Range(step.rng.lo + s, min(step.rng.lo + s + chunk_size, step.rng.hi))
-                        cs = family.compute_stats(X[s : s + chunk_size], y[s : s + chunk_size], params)
-                        new_ids.append(store.put(family.name, sub, cs, meta={"chunked": True}))
-                        stats = cs if stats is None else stats + cs
-                else:
-                    stats = family.compute_stats(X, y, params)
-                timings.compute_s += time.perf_counter() - t0
+                with span("repro.fetch") as sp:
+                    X, y = backend.fetch(step.rng)
+                timings.io_s += sp.seconds
+                with span("repro.stats") as sp:
+                    if monoid and materialize_chunks:
+                        # fit chunk-by-chunk and materialize each chunk (§4)
+                        stats = None
+                        for s in range(0, step.rng.size, chunk_size):
+                            sub = Range(step.rng.lo + s, min(step.rng.lo + s + chunk_size, step.rng.hi))
+                            cs = family.compute_stats(X[s : s + chunk_size], y[s : s + chunk_size], params)
+                            new_ids.append(store.put(family.name, sub, cs, meta={"chunked": True}))
+                            stats = cs if stats is None else stats + cs
+                    else:
+                        stats = family.compute_stats(X, y, params)
+                timings.compute_s += sp.seconds
 
-            t0 = time.perf_counter()
-            if step.sign > 0:
-                pos = stats if pos is None else pos + stats
-            else:
-                neg = stats if neg is None else neg + stats
-            timings.merge_s += time.perf_counter() - t0
+            with span("repro.merge") as sp:
+                if step.sign > 0:
+                    pos = stats if pos is None else pos + stats
+                else:
+                    neg = stats if neg is None else neg + stats
+            timings.merge_s += sp.seconds
 
     if pos is None:
         raise RuntimeError("empty plan")
-    t0 = time.perf_counter()
-    total = pos if neg is None else pos - neg
-    model = family.solve(total, params)
-    timings.merge_s += time.perf_counter() - t0
+    with span("repro.merge") as sp:
+        total = pos if neg is None else pos - neg
+    timings.merge_s += sp.seconds
+    with span("repro.solve") as sp:
+        model = family.solve(total, params)
+    timings.merge_s += sp.seconds
     return ExecResult(model=model, stats=total, plan=plan, timings=timings,
                       materialized_ids=new_ids)
 

@@ -6,6 +6,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.core.trace import span
 from repro.kernels.common import pad_axis, round_up, use_interpret
 
 from .kernel import zt_z
@@ -23,12 +24,13 @@ def linreg_stats(X, y, *, block_n: int = 512, with_yty: bool = False):
     Accepts arbitrary (n, d); zero-pads rows (zero rows are algebra-neutral)
     and features up to lane alignment.
     """
-    X = jnp.asarray(X)
-    y = jnp.asarray(y)
-    n, d = X.shape
-    Z = jnp.concatenate([X, y[:, None].astype(X.dtype)], axis=1)
-    dp = round_up(d + 1, 128)
-    npad = round_up(max(n, block_n), block_n)
-    Z = pad_axis(pad_axis(Z, 1, dp), 0, npad)
-    A, B, yty = _linreg_stats_padded(Z, d=d, block_n=block_n)
+    with span("repro.kernel.prep"):
+        X = jnp.asarray(X)
+        y = jnp.asarray(y)
+        n, d = X.shape
+        Z = jnp.concatenate([X, y[:, None].astype(X.dtype)], axis=1)
+        dp = round_up(d + 1, 128)
+        npad = round_up(max(n, block_n), block_n)
+        Z = pad_axis(pad_axis(Z, 1, dp), 0, npad)
+        A, B, yty = _linreg_stats_padded(Z, d=d, block_n=block_n)
     return (A, B, yty) if with_yty else (A, B)

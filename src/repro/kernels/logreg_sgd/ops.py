@@ -4,6 +4,7 @@ from __future__ import annotations
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.trace import span
 from repro.kernels.common import pad_axis, round_up, use_interpret
 
 from .kernel import sgd_chunks
@@ -13,8 +14,9 @@ _VMEM_FP32_BUDGET = 1_500_000  # chunk floats pinned in VMEM (~6 MB)
 
 def logreg_sgd(X, y, *, lam: float = 1e-3, lr: float = 0.5, batch: int = 64):
     """One SGD epoch over one chunk → (d+1,) weights (bias last)."""
-    w, b = logreg_sgd_batched(X[None], y[None], lam=lam, lr=lr, batch=batch)
-    return jnp.concatenate([w[0], b[0]])
+    with span("repro.kernel.prep"):
+        w, b = _sgd_padded(X[None], y[None], lam=lam, lr=lr, batch=batch)
+        return jnp.concatenate([w[0], b[0]])
 
 
 def logreg_sgd_batched(X, y, *, lam: float = 1e-3, lr: float = 0.5, batch: int = 64):
@@ -22,6 +24,11 @@ def logreg_sgd_batched(X, y, *, lam: float = 1e-3, lr: float = 0.5, batch: int =
 
     Pads rows to a batch multiple (mask-neutral) and features to lane width.
     """
+    with span("repro.kernel.prep"):
+        return _sgd_padded(X, y, lam=lam, lr=lr, batch=batch)
+
+
+def _sgd_padded(X, y, *, lam, lr, batch):
     X = jnp.asarray(X, jnp.float32)
     y = jnp.asarray(y, jnp.float32)
     p, l, d = X.shape
