@@ -51,15 +51,9 @@ def _mnb_stats(X, y, params):
 
 def _logreg_stats(X, y, params):
     """Fit the whole segment as chunk models of size l, combined (Alg 2)."""
-    l = int(params.get("chunk_size", 10_000))
-    lam = params.get("lam", 1e-3)
-    lr = params.get("lr", 0.5)
-    backend = params.get("backend", "numpy")
-    n = len(y)
-    total = LogRegMixtureStats.zero(X.shape[1])
-    for s in range(0, n, l):
-        total = total + logreg.fit_chunk(X[s : s + l], y[s : s + l], lam=lam, lr=lr, backend=backend)
-    return total
+    return logreg.fit_chunks(X, y, int(params.get("chunk_size", 10_000)),
+                             lam=params.get("lam", 1e-3), lr=params.get("lr", 0.5),
+                             backend=params.get("backend", "numpy"))
 
 
 FAMILIES: dict[str, ModelFamily] = {

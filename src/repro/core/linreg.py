@@ -51,11 +51,13 @@ def compute_stats(X: np.ndarray, y: np.ndarray, *, backend: str = "numpy") -> Li
     if backend == "pallas":
         from repro.kernels.linreg_stats import ops as k_ops
 
-        A, B = k_ops.linreg_stats(np.asarray(X, np.float32), np.asarray(y, np.float32))
+        G = k_ops.linreg_gram(X, y)
         with trace.span("repro.kernel.sync"):
-            trace.count("repro.device_reads", 2)
-            A, B = np.asarray(A, np.float64), np.asarray(B, np.float64)
-        return LinRegStats(n=np.asarray(float(X.shape[0]), np.float64), A=A, B=B)
+            trace.count("repro.device_reads")
+            G = np.asarray(G, np.float64)
+        d = G.shape[0] - 1
+        return LinRegStats(n=np.asarray(float(X.shape[0]), np.float64),
+                           A=G[:d, :d], B=G[:d, d])
     raise ValueError(f"unknown backend {backend!r}")
 
 

@@ -68,11 +68,11 @@ def sgd_pass(
     if backend == "pallas":
         from repro.kernels.logreg_sgd import ops as k_ops
 
-        w = k_ops.logreg_sgd(np.asarray(X, np.float32), np.asarray(y, np.float32),
-                             lam=lam, lr=lr, batch=batch)
+        W = k_ops.logreg_sgd_chunks(X, y, chunk=max(len(y), 1), lam=lam, lr=lr,
+                                    batch=batch)
         with trace.span("repro.kernel.sync"):
             trace.count("repro.device_reads")
-            return np.asarray(w, np.float64)
+            return np.asarray(W, np.float64)[0]
     X = np.asarray(X, np.float64)
     y = np.asarray(y, np.float64)
     n, d = X.shape
@@ -96,6 +96,31 @@ def fit_chunk(X, y, lam: float = 1e-3, lr: float = 0.5, *, backend: str = "numpy
     """Materialize one chunk model (Alg 2 line 11)."""
     w = sgd_pass(X, y, lam=lam, lr=lr, backend=backend)
     return LogRegMixtureStats.from_chunk_weights(w, n_points=len(y))
+
+
+def fit_chunks(X, y, chunk_size: int = 10_000, lam: float = 1e-3, lr: float = 0.5, *,
+               backend: str = "numpy") -> LogRegMixtureStats:
+    """A scan as chunk models of ``chunk_size`` rows, combined in order
+    (Alg 2 lines 9-11).  ``backend="pallas"`` runs every chunk in one
+    kernel call and reads the weights back once."""
+    n = len(y)
+    total = LogRegMixtureStats.zero(X.shape[1])
+    starts = range(0, n, chunk_size)
+    if backend == "pallas" and n:
+        from repro.kernels.logreg_sgd import ops as k_ops
+
+        W = k_ops.logreg_sgd_chunks(X, y, chunk=chunk_size, lam=lam, lr=lr)
+        with trace.span("repro.kernel.sync"):
+            trace.count("repro.device_reads")
+            W = np.asarray(W, np.float64)
+        for w, s in zip(W, starts):
+            total = total + LogRegMixtureStats.from_chunk_weights(
+                w, n_points=min(chunk_size, n - s))
+        return total
+    for s in starts:
+        total = total + fit_chunk(X[s : s + chunk_size], y[s : s + chunk_size],
+                                  lam=lam, lr=lr, backend=backend)
+    return total
 
 
 def solve(stats: LogRegMixtureStats, lam: float = 1e-3) -> LogRegModel:

@@ -59,13 +59,12 @@ def compute_gaussian_stats(X, y, n_classes: int, *, backend: str = "numpy") -> G
     if backend == "pallas":
         from repro.kernels.nb_stats import ops as k_ops
 
-        counts, S, SS = k_ops.nb_stats(
-            np.asarray(X, np.float32), np.asarray(y, np.int32), n_classes
-        )
+        G = k_ops.nb_grouped(X, y, n_classes)
         with trace.span("repro.kernel.sync"):
-            trace.count("repro.device_reads", 3)
-            counts, S, SS = (np.asarray(a, np.float64) for a in (counts, S, SS))
-        return GaussianNBStats(counts=counts, S=S, SS=SS)
+            trace.count("repro.device_reads")
+            G = np.asarray(G, np.float64)
+        d = X.shape[1]
+        return GaussianNBStats(counts=G[:, 0], S=G[:, 1 : 1 + d], SS=G[:, 1 + d :])
     raise ValueError(f"unknown backend {backend!r}")
 
 

@@ -40,9 +40,12 @@ def _kernel(x_ref, y_ref, m_ref, w_ref, b_ref, *, lam: float, lr: float, batch: 
         z = jax.lax.dot_general(w, xb, contract_d, precision=_F32,
                                 preferred_element_type=jnp.float32) + b
         g = (jax.nn.sigmoid(z) - yb) * mb
-        denom = jnp.maximum(mb.sum(axis=1, keepdims=True), 1.0)
+        live = mb.sum(axis=1, keepdims=True)
+        denom = jnp.maximum(live, 1.0)
         tf = jnp.full((1, 1), t, jnp.int32).astype(jnp.float32)
-        step = lr / jnp.sqrt(tf + 1.0)
+        # a minibatch with no real row (a short chunk's tail) takes no step:
+        # the decay 2·lam·w alone would otherwise still move w
+        step = jnp.where(live > 0.0, lr / jnp.sqrt(tf + 1.0), 0.0)
         gw = jax.lax.dot_general(g, xb, contract_b, precision=_F32,
                                  preferred_element_type=jnp.float32) / denom
         gw = gw + 2.0 * lam * w
@@ -63,7 +66,8 @@ def sgd_chunks(x, y, mask, *, lam: float, lr: float, batch: int, interpret: bool
     """Run one SGD epoch per chunk.
 
     ``x`` (p, l, d); ``y``/``mask`` (p, l // batch, batch) — one row per
-    minibatch, so the kernel reads minibatch t as a row slice.  Returns
+    minibatch, so the kernel reads minibatch t as a row slice; a minibatch
+    whose mask is all zero leaves the weights as they were.  Returns
     weights (p, 1, d) and bias (p, 1, 128), the bias broadcast across its
     lane row.  Every block's trailing two dims equal the array's, which is
     the TPU tiling rule for blocks narrower than (8, 128).

@@ -30,7 +30,8 @@ def logreg_sgd_ref(X, y, mask, *, lam: float, lr: float, batch: int):
         z = xb @ w + b
         g = (jax.nn.sigmoid(z) - yb) * mb
         denom = jnp.maximum(mb.sum(), 1.0)
-        step = lr / jnp.sqrt(t.astype(jnp.float32) + 1.0)
+        # a minibatch with no real row takes no step, as in the kernel
+        step = jnp.where(mb.sum() > 0.0, lr / jnp.sqrt(t.astype(jnp.float32) + 1.0), 0.0)
         gw = xb.T @ g / denom + 2.0 * lam * w
         gb = g.sum() / denom
         return (w - step * gw, b - step * gb)

@@ -13,6 +13,7 @@ This is the only file that describes the topology, and it does so inside
 a fixture, so that every pytest worker collects the same tests and only
 the one that runs this file loads the TPU compiler.
 """
+import importlib
 import os
 
 import pytest
@@ -126,3 +127,33 @@ def test_kernel_compiles_for_v5e(name, one_chip, no_compile_cache):
     compiled = _compile(fn, one_chip, *shapes)
     mem = compiled.memory_analysis()
     assert mem is not None and mem.argument_size_in_bytes > 0
+
+
+#: the statistics wrappers' jitted programs, which take each scan's packed
+#: buffer flat and reshape, lane-pad and slice around the kernel
+WRAPPERS = {
+    # a 65,536-row bucket of [X | y], 10 features
+    "linreg_gram": ("repro.kernels.linreg_stats.ops",
+                    lambda m, f: m._gram(f, w=11, block_n=512), 65_536 * 11),
+    # the same bucket of [X | label], 2 classes
+    "nb_grouped": ("repro.kernels.nb_stats.ops",
+                   lambda m, f: m._grouped(f, w=11, n_classes=2, block_n=512),
+                   65_536 * 11),
+    # 8 chunk slots of 10,048 rows of [X | y | mask]
+    "logreg_sgd_chunks": ("repro.kernels.logreg_sgd.ops",
+                          lambda m, f: m._sgd(f, shape=(8, 10_048, 12), lam=1e-3,
+                                              lr=0.5, batch=64),
+                          8 * 10_048 * 12),
+}
+
+
+@pytest.mark.parametrize("name", sorted(WRAPPERS))
+def test_wrapper_program_compiles_for_v5e(name, one_chip, no_compile_cache,
+                                          monkeypatch):
+    mod, call, size = WRAPPERS[name]
+    ops = importlib.import_module(mod)
+    monkeypatch.setattr(ops, "use_interpret", lambda: False)
+    try:
+        _compile(lambda f: call(ops, f), one_chip, ((size,), F32))
+    finally:
+        jax.clear_caches()      # no program traced for the chip stays cached

@@ -4,8 +4,9 @@ Off, a span keeps nothing and writes no profiler annotation, and
 ``ExecTimings`` is still filled.  On, spans nest with their parent and
 query id, self times subtract the children, and the five layers the spans
 mark (planner, data backend and store, kernels' wrappers, device reads,
-engine) split each ``repro.query`` exactly; ``repro.device_reads`` counts
-the arrays each kernel call reads back.
+engine) split each ``repro.query`` exactly; each scan a plan makes is one
+kernel call (``repro.kernel.calls``) and one device read
+(``repro.device_reads``).
 """
 import math
 import time
@@ -20,6 +21,7 @@ from repro.core.descriptors import Range
 from repro.core.engine import IncrementalAnalyticsEngine
 from repro.data.synthetic import make_classification, make_regression
 from repro.data.tabular import ArrayBackend
+from repro.kernels.common import round_up, row_bucket
 
 FAMILIES = ("linreg", "gaussian_nb", "logreg")
 #: the layers a query's spans fall into, as the benchmark reads them
@@ -30,8 +32,6 @@ LAYERS = {
     "sync": ("repro.kernel.sync",),
     "engine": ("repro.query", "repro.stats", "repro.merge", "repro.solve"),
 }
-#: arrays read back to the host per kernel call
-READS = {"linreg": 2, "gaussian_nb": 3, "logreg": 1}
 
 
 class FakeAnnotation:
@@ -188,10 +188,13 @@ def test_device_reads_per_kernel_call(family):
     got = [eng.query(family, r, **params) for r in QUERIES]
     scans = [s.rng.size for q in got for s in q.plan.steps if s.model_id is None]
     assert scans
-    if family == "logreg":
-        calls = sum(math.ceil(n / params["chunk_size"]) for n in scans)
-    else:
-        calls = len(scans)
     counters = trace.summary()["counters"]
-    assert counters["repro.device_reads"] == READS[family] * calls
-    assert trace.summary()["spans"]["repro.kernel.sync"]["count"] == calls
+    assert counters["repro.kernel.calls"] == len(scans)
+    assert counters["repro.device_reads"] == len(scans)
+    assert trace.summary()["spans"]["repro.kernel.sync"]["count"] == len(scans)
+    if family == "logreg":
+        l = params["chunk_size"]
+        slots = sum(math.ceil(n / l) * round_up(l, 64) for n in scans)
+    else:
+        slots = sum(row_bucket(n, 512) for n in scans)
+    assert counters["repro.kernel.rows_padded"] == slots - sum(scans)
