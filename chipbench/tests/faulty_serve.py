@@ -1,0 +1,60 @@
+"""Run a serving cell with the timed path broken underneath, for the tests.
+
+    python faulty_serve.py <fault> <run.py arguments...>
+
+``token``: every served token is replaced by the next id, where it is
+sampled.  ``kv``: every prompt's cache has the KV of its last chunk
+(``chunk_tokens`` positions before the prompt's last token) zeroed, where
+it is built.
+"""
+from __future__ import annotations
+
+import os
+import sys
+from pathlib import Path
+
+os.environ["JAX_PLATFORMS"] = "cpu"     # before anything imports JAX
+
+HERE = Path(__file__).resolve().parent
+sys.path.insert(0, str(HERE.parent))
+sys.path.insert(0, str(HERE.parent.parent / "src"))
+
+
+def break_tokens() -> None:
+    from repro.serve.session import SessionManager
+
+    orig = SessionManager._sample
+
+    def sample(self, s):
+        orig(self, s)
+        tok = (s.out_tokens[-1] + 1) % self.model.cfg.vocab_size
+        s.out_tokens[-1] = s.next_tok = tok
+    SessionManager._sample = sample
+
+
+def break_kv() -> None:
+    import jax
+
+    from repro.serve.engine import PrefixCacheBuilder
+
+    orig = PrefixCacheBuilder.build_prefix
+
+    def build(self, doc, length, **kw):
+        out = orig(self, doc, length, **kw)
+        lo = max(length - self.chunk, 0)
+
+        def zero(path, x):
+            if getattr(path[-1], "key", None) in ("k", "v"):
+                return x.at[:, :, lo:length].set(0)
+            return x
+        caches = jax.tree_util.tree_map_with_path(zero, out[0])
+        return (caches, *out[1:])
+    PrefixCacheBuilder.build_prefix = build
+
+
+if __name__ == "__main__":
+    fault = sys.argv[1]
+    {"token": break_tokens, "kv": break_kv}[fault]()
+    import run
+
+    sys.exit(run.main(sys.argv[2:]))
