@@ -12,17 +12,17 @@ is one trace drawn from the layout seed (``arrivals.schedule``), replayed
 for every seed, so every seed offers the same work.
 
 Set-up makes the weights on the device in one jitted call, then warms the
-program: one pass of ``max_batch`` requests whose answers end one step
-apart (every decode batch size and every pack split), one request on each
-document of the pool, least popular first (every prompt shape, and a store
-filled as steady traffic leaves it; where the traffic names
-``resident_ranks``, the documents below them are then retired whole, so
-the store holds the head of the pool), and an open-loop lead-in at the
-cell's rate.  The window then offers the requests due in ``--seconds`` at
-that rate; time to first token counts from when a request was due, each
-gap between tokens as the client sees it after a scheduler round.  The
-window's requests drain after it; one still open ``drain_s`` after the
-window closed has failed.
+program: one request on each document of the pool, least popular first
+(every prompt shape, and a store left as steady traffic leaves it: the
+head of the pool resident, and every document's put and hit counts, which
+the store's retention scores read), one pass of ``max_batch`` requests
+whose answers end one step apart (every decode batch size and every pack
+split), and an open-loop lead-in at the cell's rate, long enough for the
+store's evictions per request to level off.  The window then offers the
+requests due in ``--seconds`` at that rate; time to first token counts
+from when a request was due, each gap between tokens as the client sees
+it after a scheduler round.  The window's requests drain after it; one
+still open ``drain_s`` after the window closed has failed.
 
 ``correct``: a sample of the window's requests drawn from the seed, with
 the longest answer among them, is run through the plain float32 reference
@@ -30,6 +30,9 @@ the longest answer among them, is run through the plain float32 reference
 widest gap by which a served token's logit lies below the reference's best
 at its position, and the worst relative distance of the served logit rows
 (first token, decode positions 1, n/2 and the last) from the reference's.
+The window's first request whose plan computes its document's first chunk
+alone before a stored segment (:func:`cold_first_chunk`) is compared too,
+sampled or not.
 """
 # no ``from __future__ import annotations``: the harness loads drivers by
 # file name, outside ``sys.modules``, where dataclasses cannot resolve
@@ -122,6 +125,8 @@ class Request:
     tokens: list = field(default_factory=list)
     reused: int = 0
     computed: int = 0
+    cold_first: bool = False    # see cold_first_chunk
+    window: bool = False        # due inside the measured window
     done: bool = False
     #: token index -> served logits (numpy, or a device array until read)
     logits: dict = field(default_factory=dict)
@@ -155,13 +160,16 @@ class Server:
             decode_materialize=bool(prog["decode_materialize"]))
         self.late: list = []
         self.doc_ids: dict = {}     # popularity rank -> the program's doc id
+        self.cold_first = 0         # plans counted by cold_first_chunk
+        self.witness = None         # the window's first such request
 
     def counters(self) -> dict:
         sc = self.mgr.sched
         return {"decode_calls": sc.decode_calls, "decode_rows": sc.decode_rows,
                 "decode_valid_tokens": sc.decode_valid_tokens,
                 "pack_rebuilds": sc.pack_rebuilds,
-                "evictions": self.mgr.store.evictions}
+                "evictions": self.mgr.store.evictions,
+                "cold_first_chunk_plans": self.cold_first}
 
     def _submit(self, r: Request, now: float, due_at: float) -> None:
         with harness.span("cb.submit"):
@@ -171,6 +179,12 @@ class Server:
             plan = self.mgr.submit(r.sid, len(doc), r.n_new, greedy=True)
         r.submitted, r.due_at = now, due_at
         r.gaps = [(s.rng.lo, s.rng.hi) for s in plan.steps if s.model_id is None]
+        r.cold_first = cold_first_chunk(plan.steps,
+                                        int(self.cell["program"]["chunk_tokens"]))
+        self.cold_first += r.cold_first
+        if r.cold_first and r.window and self.witness is None:
+            keep_rows(r)
+            self.witness = r
         if 0 in r.want:
             r.logits[0] = self.mgr.sessions[r.sid].logits
 
@@ -231,21 +245,28 @@ class Server:
             self.mgr.close_session(sid)
 
 
+def cold_first_chunk(steps, chunk: int) -> bool:
+    """A plan that computes its document's first chunk, and nothing more,
+    before a stored segment: what a store leaves once it has evicted a
+    first chunk alone, the program's cold-start path followed by an insert."""
+    steps = sorted(steps, key=lambda s: s.rng.lo)
+    return (len(steps) > 1 and steps[0].model_id is None
+            and steps[0].rng.lo == 0 and steps[0].rng.hi <= chunk)
+
+
 def requests(sched: list) -> list:
     return [Request(due, rank, n) for due, rank, n in sched]
 
 
-def warm(srv: Server, tr: dict, max_batch: int) -> dict:
+def warm(srv: Server, max_batch: int) -> dict:
     """Set-up's passes over the program (module docstring): seconds each."""
     out = {}
     t = time.perf_counter()
     # every document once, least popular first: every prompt shape, and the
     # store holding what steady traffic keeps
-    n_docs = int(tr["docs"])
-    srv.serve([Request(0.0, r, 1) for r in range(n_docs - 1, -1, -1)], 0.0,
-              max_live=max_batch)
+    srv.serve([Request(0.0, r, 1) for r in range(len(srv.docs) - 1, -1, -1)],
+              0.0, max_live=max_batch)
     out["pool_pass_s"] = time.perf_counter() - t
-    retire_tail(srv, tr)
     # every decode batch size and pack split: prompts built from the store,
     # answers long enough that all are decoding together before the first
     # ends, and ending one step apart
@@ -256,14 +277,6 @@ def warm(srv: Server, tr: dict, max_batch: int) -> dict:
     return out
 
 
-def retire_tail(srv: Server, tr: dict) -> None:
-    """Where the traffic names ``resident_ranks``: the store as a server
-    that has served only the head of the pool holds it, the documents
-    below those ranks retired whole."""
-    for rank in range(int(tr.get("resident_ranks", tr["docs"])), int(tr["docs"])):
-        srv.mgr.store.release_doc(srv.doc_ids[rank])
-
-
 def offered(tr: dict, rate: float, seconds: float) -> tuple[list, list]:
     """The lead-in's requests (due before the window opens at 0) and the
     window's."""
@@ -271,7 +284,10 @@ def offered(tr: dict, rate: float, seconds: float) -> tuple[list, list]:
     lead = requests(arrivals.schedule(tr, rate, lead_s, SALT_LEAD))
     for r in lead:
         r.due -= lead_s
-    return lead, requests(arrivals.schedule(tr, rate, seconds, SALT_WINDOW))
+    reqs = requests(arrivals.schedule(tr, rate, seconds, SALT_WINDOW))
+    for r in reqs:
+        r.window = True
+    return lead, reqs
 
 
 def sample(reqs: list, seed: int, k: int) -> list:
@@ -282,8 +298,23 @@ def sample(reqs: list, seed: int, k: int) -> list:
     rest = [i for i in rng.permutation(len(reqs)) if i != longest][:max(k - 1, 0)]
     picks = [reqs[i] for i in sorted([longest, *rest])]
     for r in picks:
-        r.want = tuple(sorted({0, 1, r.n_new // 2, r.n_new - 1}))
+        keep_rows(r)
     return picks
+
+
+def keep_rows(r: Request) -> None:
+    """Keep ``r``'s served logits at tokens 0, 1, n/2 and n-1."""
+    r.want = tuple(sorted({0, 1, r.n_new // 2, r.n_new - 1}))
+
+
+def compared(srv: Server, picks: list) -> list:
+    """The requests ``correct`` compares, each with its kept logit rows:
+    the sample, and the window's first plan that computed a first chunk
+    alone before a stored segment, where there is one."""
+    w = srv.witness
+    rs = picks + [w] if w is not None and all(r is not w for r in picks) else picks
+    return [(r, {k: np.asarray(v, np.float32).reshape(-1)
+                 for k, v in r.logits.items()}) for r in rs]
 
 
 def pct(xs, q: float) -> float:
@@ -299,7 +330,7 @@ def run(run: harness.Run, trace_dir) -> None:
     print(f"set-up: weights and program in "
           f"{time.perf_counter() - run.notes['t_start']:.1f} s",
           file=sys.stderr, flush=True)
-    info = warm(srv, tr, int(cell["program"]["max_batch"]))
+    info = warm(srv, int(cell["program"]["max_batch"]))
     c = srv.compiles.snapshot()
     print(f"set-up: {json.dumps(info)}; {c[0]} programs built ({c[1]} "
           f"compiled, {srv.compiles.compile_s:.1f} s)", file=sys.stderr, flush=True)
@@ -333,8 +364,7 @@ def run(run: harness.Run, trace_dir) -> None:
     run.memory_peak_bytes = (run.device.memory_stats() or {}).get(
         "peak_bytes_in_use", 0)
     measure(run, srv, reqs, marks, cell, tr)
-    captured = [(r, {k: np.asarray(v, np.float32).reshape(-1)
-                     for k, v in r.logits.items()}) for r in picks]
+    captured = compared(srv, picks)
     docs = srv.docs
     del srv, lead, reqs
     gc.unfreeze()
@@ -397,6 +427,8 @@ def measure(run, srv: Server, reqs: list, marks: dict, cell: dict, tr: dict) -> 
         tokens_reused=reused, tokens_computed=computed,
         decode_calls=d["decode_calls"], decode_rows=d["decode_rows"],
         pack_rebuilds=d["pack_rebuilds"], evictions=d["evictions"],
+        cold_first_chunk_plans=d["cold_first_chunk_plans"],
+        setup_cold_first_chunk_plans=c0["cold_first_chunk_plans"],
         setup_programs=k0[0], setup_compiles=k0[1],
         window_programs=k1[0] - k0[0], window_compiles=k1[1] - k0[1],
         met_share=met / max(len(reqs), 1),
@@ -469,15 +501,14 @@ def check(seed: int, cfg: dict, cell: dict, docs: list, captured: list,
 
 
 def sweep(run: harness.Run) -> int:
-    """One set-up, then a window at each of the cell's ``sweep_rates`` (the
-    store reset to set-up's state before each): per rate, one JSON line
+    """One set-up, then a window at each of the cell's ``sweep_rates``, in
+    order, the store carried from one to the next: per rate, one JSON line
     with the share of requests that met both limits, the tails, failures
     and the requests still open when the window closed (the backlog)."""
     cell, tr, cfg = harness.cell_spec(run)
     srv = Server(run.seed, cell, tr, cfg, run.notes["compiles"])
-    warm(srv, tr, int(cell["program"]["max_batch"]))
+    warm(srv, int(cell["program"]["max_batch"]))
     for rate in cell["sweep_rates"]:
-        retire_tail(srv, tr)
         lead, reqs = offered(tr, float(rate), run.seconds)
         srv.late.clear()
         marks: dict = {}
@@ -523,14 +554,13 @@ def tool(run: harness.Run, mode: str, count: int = 1) -> int:
         raise harness.BenchError(f"no tool mode {mode!r} in serve_open")
     cell, tr, cfg = harness.cell_spec(run)
     srv = Server(run.seed, cell, tr, cfg, run.notes["compiles"])
-    warm(srv, tr, int(cell["program"]["max_batch"]))
+    warm(srv, int(cell["program"]["max_batch"]))
     lead, reqs = offered(tr, float(cell["rate_per_s"]), run.seconds)
     picks = sample(reqs, run.seed, int(cell["check"]["sample"]))
     t0 = time.perf_counter() + float(tr["lead_in_s"])
     srv.serve(lead + reqs, t0, deadline=t0 + run.seconds + float(tr["drain_s"]))
-    captured = [(r, {k: np.asarray(v, np.float32).reshape(-1)
-                     for k, v in r.logits.items()}) for r in picks]
-    docs = srv.docs
+    captured = compared(srv, picks)
+    docs, cold = srv.docs, sum(r.cold_first for r in reqs)
     del srv
     gc.collect()
     jax.clear_caches()
@@ -541,6 +571,8 @@ def tool(run: harness.Run, mode: str, count: int = 1) -> int:
             "control": {c.name: c.value for c in got["control"]},
             "control_correct": all(c.ok for c in got["control"]),
             "served_tokens": sum(len(r.tokens) for r, _ in captured),
-            "reference_s": got["reference_s"]}
+            "reference_s": got["reference_s"],
+            "cold_first_chunk_plans": cold,
+            "compared": len(captured)}
     print(json.dumps(line), flush=True)
     return 0

@@ -5,7 +5,10 @@
 ``token``: every served token is replaced by the next id, where it is
 sampled.  ``kv``: every prompt's cache has the KV of its last chunk
 (``chunk_tokens`` positions before the prompt's last token) zeroed, where
-it is built.
+it is built.  ``first_chunk``: before every plan the store drops the
+document's first stored segment, where it holds more of the document, so
+the plan computes the first chunk alone before a stored segment: the path
+of the program's own first-chunk fault.
 """
 from __future__ import annotations
 
@@ -52,9 +55,26 @@ def break_kv() -> None:
     PrefixCacheBuilder.build_prefix = build
 
 
+def break_first_chunk() -> None:
+    from repro.serve.session import SessionManager
+
+    orig = SessionManager.submit
+
+    def submit(self, sid, *args, **kw):
+        doc_id = self.sessions[sid].doc_id
+        segs = sorted((s for s in self.store._segs.values()
+                       if doc_id in s.doc_ids()), key=lambda s: s.rng.lo)
+        if len(segs) > 1 and segs[0].rng.lo == 0 \
+                and segs[0].seg_id not in self.store._pins:
+            self.store._evict(segs[0])
+        return orig(self, sid, *args, **kw)
+    SessionManager.submit = submit
+
+
 if __name__ == "__main__":
     fault = sys.argv[1]
-    {"token": break_tokens, "kv": break_kv}[fault]()
+    {"token": break_tokens, "kv": break_kv,
+     "first_chunk": break_first_chunk}[fault]()
     import run
 
     sys.exit(run.main(sys.argv[2:]))

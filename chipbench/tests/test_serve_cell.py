@@ -6,7 +6,7 @@ plain and traced, and prints the contract line; the precision control and
 two faults of the timed path (a served token altered, a prompt chunk's KV
 zeroed) make ``correct`` come out false; the work counts match hand
 numbers; the schedules give every seed the same work; the configuration
-keeps the program's published widths; the pool fits its store.
+keeps the program's published widths; the pool outgrows its store.
 
 Run:  PYTHONPATH=src python -m pytest chipbench/tests/test_serve_cell.py -q
 """
@@ -28,7 +28,7 @@ sys.path.insert(0, str(BENCH))
 
 from test_chipbench import SEED, _check_line, _env, _run  # noqa: E402
 
-CELL = "ds67b-docqa-fit"
+CELL = "ds67b-docqa-shared"
 
 
 def _spec(kind: str, name: str) -> dict:
@@ -152,13 +152,56 @@ def test_precision_control_is_not_correct(tmp_path):
     assert any(got["control"][k] > v for k, v in limits.items())
 
 
-@pytest.mark.parametrize("fault", ["token", "kv"])
+@pytest.mark.parametrize("fault", ["token", "kv", "first_chunk"])
 def test_broken_timed_path_is_not_correct(tmp_path, fault):
     line, err = _run(tmp_path, [fault, "--workload", CELL, "--seed", str(SEED + 2),
                                 "--seconds", "2", "--trace", "0"],
                      script=HERE / "faulty_serve.py")
     assert line["correct"] is False, err[-2000:]
     assert any(c["value"] > c["limit"] for c in line["checks"].values())
+    if fault == "first_chunk":
+        window = json.loads(err.split("failed; ", 1)[1].splitlines()[0])
+        assert window["cold_first_chunk_plans"] > 0
+
+
+def test_the_windows_first_cold_first_chunk_plan_is_compared():
+    """The first window request whose plan computes the first chunk alone
+    before a stored segment keeps its logit rows and is compared beside the
+    sample; a lead-in request and later ones are not."""
+    from types import SimpleNamespace as NS
+
+    so = _driver()
+    cold = [NS(rng=NS(lo=0, hi=128), model_id=None),
+            NS(rng=NS(lo=128, hi=512), model_id="s")]
+    kinds = [cold, [NS(rng=NS(lo=0, hi=512), model_id="s")], cold, cold]
+
+    class Mgr:
+        def __init__(self):
+            self.sessions = {}
+
+        def add_session(self, doc):
+            sid = len(self.sessions)
+            self.sessions[sid] = NS(doc_id=f"d{sid}", logits=np.ones(3))
+            return sid
+
+        def submit(self, sid, n, n_new, greedy):
+            return NS(steps=kinds[sid])
+
+    srv = so.Server.__new__(so.Server)
+    srv.docs, srv.mgr, srv.doc_ids = [np.ones(513, np.int32)], Mgr(), {}
+    srv.cell, srv.cold_first, srv.witness = {"program": {"chunk_tokens": 128}}, 0, None
+    lead = so.Request(-1.0, 0, 8)
+    reqs = [so.Request(t, 0, 8, window=True) for t in (0.0, 1.0, 2.0)]
+    picks = [reqs[0]]
+    so.keep_rows(reqs[0])
+    for r in (lead, *reqs):
+        srv._submit(r, 0.0, 0.0)
+    assert srv.cold_first == 3 and srv.witness is reqs[1]
+    assert reqs[1].want == (0, 1, 4, 7) and 0 in reqs[1].logits
+    assert not lead.want and not reqs[2].want
+    assert [id(r) for r, _ in so.compared(srv, picks)] == [id(reqs[0]), id(reqs[1])]
+    srv.witness = reqs[0]
+    assert [id(r) for r, _ in so.compared(srv, picks)] == [id(reqs[0])]
 
 
 # -- work counts, schedules, sizes ---------------------------------------------------
@@ -190,19 +233,36 @@ def test_serve_work_by_hand():
         ("extend", 512, 128), ("extend", 640, 60), ("extend", 700, 1)]
 
 
+def test_cold_first_chunk_plans_are_named():
+    """Only a plan that computes the first chunk alone, then reuses a
+    stored segment, is counted; a longer cold start, a whole cold build and
+    a plan that starts from the store are not."""
+    from types import SimpleNamespace as NS
+
+    so = _driver()
+
+    def plan(*steps):
+        return [NS(rng=NS(lo=lo, hi=hi), model_id=m) for lo, hi, m in steps]
+    assert so.cold_first_chunk(plan((128, 256, "s1"), (0, 128, None)), 128)
+    assert so.cold_first_chunk(plan((0, 64, None), (64, 256, "s1")), 128)
+    assert not so.cold_first_chunk(plan((0, 256, None), (256, 384, "s1")), 128)
+    assert not so.cold_first_chunk(plan((0, 128, None)), 128)
+    assert not so.cold_first_chunk(plan((0, 128, "s0"), (128, 200, None)), 128)
+
+
 def test_schedule_is_one_trace_at_fixed_quantiles():
     """The same requests at the same times for every seed: counts per
     document and answer lengths at fixed quantiles, due inside the window."""
     import arrivals
     import traffic
 
-    tr = _spec("traffic", "docqa-fit")
+    tr = _spec("traffic", "docqa-shared")
     a = arrivals.schedule(tr, 3.0, 30, 24)
     assert a == arrivals.schedule(tr, 3.0, 30, 24)
     assert len(a) == 90 and a[0][0] == 0 and a[-1][0] < 30
     assert sorted(x[2] for x in a) == list(traffic.sizes(tr["answer_len"], 90))
     ranks = [x[1] for x in a]
-    assert ranks.count(0) > ranks.count(1) > ranks.count(5) and max(ranks) < 80
+    assert ranks.count(0) > ranks.count(1) > ranks.count(5) and max(ranks) < 130
     assert arrivals.schedule(tr, 3.0, 8, 23) != a[:24]      # the lead-in's own
 
 
@@ -220,16 +280,31 @@ def test_configuration_keeps_the_published_widths():
     assert dataclasses.replace(got, name=full.name, norm_eps=full.norm_eps) == full
 
 
-def test_pool_fits_its_store():
-    """Every document of the workload's pool, stored as the program pads
-    segments, fits the store's budget: nothing is ever evicted."""
+def _padded_pool_bytes(tr: dict, prog: dict, kv_bytes: int) -> int:
+    """The pool's segments as the program stores them: whole chunks,
+    the ragged end of each prompt build in ``segment_bucket`` positions."""
     so = _driver()
-    tr, cell = _spec("traffic", "docqa-fit"), _spec("workloads", CELL)
-    prog = cell["program"]
     chunk, seg = prog["chunk_tokens"], prog["segment_bucket"]
-    n = so.ref.dims(_spec("configs", "deepseek-67b-4L"))
-    lens = so.pool_lengths(tr)
-    assert lens.min() >= 1024 and lens.max() <= 3584 and not (lens % 64).any()
     padded = sum((L - 1) // chunk * chunk + -(-((L - 1) % chunk) // seg) * seg
-                 for L in lens)
-    assert padded * so.work.kv_bytes_per_token(n) <= prog["byte_budget"]
+                 for L in so.pool_lengths(tr))
+    return int(padded) * kv_bytes
+
+
+def test_pool_outgrows_its_store():
+    """The workload's pool, stored as the program pads segments, is about
+    1.5 times the store's budget: the store evicts, at the cell's size and
+    in the rehearsal."""
+    so = _driver()
+    tr, cell = _spec("traffic", "docqa-shared"), _spec("workloads", CELL)
+    cfg = _spec("configs", "deepseek-67b-4L")
+    lens = so.pool_lengths(tr)
+    assert len(lens) == 130
+    assert lens.min() >= 1024 and lens.max() <= 3584 and not (lens % 64).any()
+    prog = cell["program"]
+    got = _padded_pool_bytes(tr, prog, so.work.kv_bytes_per_token(so.ref.dims(cfg)))
+    assert got == 279_040 * 16_384
+    assert 1.4 <= got / prog["byte_budget"] <= 1.6
+    r = cell["rehearse"]
+    tiny = so.work.kv_bytes_per_token(so.ref.dims(_tiny_cfg()))
+    got = _padded_pool_bytes({**tr, **r["traffic"]}, r["program"], tiny)
+    assert 1.4 <= got / r["program"]["byte_budget"] <= 1.6
