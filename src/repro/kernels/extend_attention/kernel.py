@@ -5,11 +5,15 @@ The serving engine realizes the paper's reuse plan by *extending* a cached
 prefix with an uncovered chunk: the chunk's q rows attend over
 [cached prefix ‖ new chunk].  On TPU that inner loop is this kernel:
 
-  * one grid step = one (batch·head) stream — maps onto the mesh's
-    data/model axes at the distribution layer;
-  * the q chunk (≤512×hd) is pinned in VMEM; the KV stream is walked in
+  * one grid step = one (batch·head) stream and one tile of at most
+    ``Q_TILE`` query positions — the stream axis maps onto the mesh's
+    data/model axes at the distribution layer, and a stream's KV block
+    index does not change between its q tiles, so the block is fetched
+    once per stream however many tiles read it;
+  * the q tile is pinned in VMEM; the KV stream is walked in
     ``chunk``-sized VMEM tiles with online softmax (m, l, acc carries in
-    registers/VMEM — nothing quadratic is ever materialized);
+    registers/VMEM — nothing quadratic is ever materialized), so VMEM is
+    bounded by the tile, not by the number of query positions;
   * the causal boundary only affects the trailing ``nb`` positions, so all
     fully-cached tiles run mask-free on the MXU;
   * ``t_real`` — the valid KV length — is a **runtime scalar** (SMEM via
@@ -30,20 +34,33 @@ from repro.kernels.common import pad_axis, round_up
 
 NEG_INF = -1e30
 
+#: query positions per grid step: a 128-position tile of 8 GQA heads is
+#: 1,024 q rows, whose (rows, chunk) f32 scores fit VMEM at head dim 128
+Q_TILE = 128
 
-def _kernel(t_ref, q_ref, k_ref, v_ref, o_ref, *, chunk: int, groups: int):
+
+def q_tile(nb: int) -> int:
+    """Query positions per tile: ``Q_TILE`` where it divides ``nb``, else
+    all ``nb`` in one tile (ragged and one-token extends)."""
+    return Q_TILE if nb % Q_TILE == 0 else nb
+
+
+def _kernel(t_ref, q_ref, k_ref, v_ref, o_ref, *, chunk: int, groups: int,
+            nb: int):
     rows, hd = q_ref.shape[1], q_ref.shape[2]
-    nb = rows // groups              # q rows per sequence position
+    tq = rows // groups              # q positions in this tile
     hd_v = v_ref.shape[2]
     t_pad = k_ref.shape[1]
     n_chunks = t_pad // chunk
     t_real = t_ref[0]                                    # runtime valid length
 
     q = q_ref[0].astype(jnp.float32) * (hd ** -0.5)      # (rows, hd) in VMEM
-    # GQA: the stream carries all `groups` query heads of one KV head,
-    # stacked as row r = g·nb + i — so row r's sequence position is r mod nb
+    # GQA: the tile carries all `groups` query heads of one KV head,
+    # stacked as row r = g·tq + i — so row r's position in the tile is
+    # r mod tq, and tile j starts tq·j positions into the nb-long block
     row = jax.lax.broadcasted_iota(jnp.int32, (rows, chunk), 0)
-    q_pos = (t_real - nb) + (row % nb if groups > 1 else row)
+    q_pos = (t_real - nb + pl.program_id(1) * tq) + (
+        row % tq if groups > 1 else row)
 
     def body(i, carry):
         m, l, acc = carry
@@ -81,12 +98,16 @@ def extend_attention_streams(q, k, v, *, t_real, chunk: int = 512,
     length is accepted.
 
     ``groups`` > 1 is the GQA layout: one stream carries all G query heads
-    of a single KV head, stacked along the q-row axis (row g·nb + i is head
-    g at sequence position i) — the KV stream is read once per *group*
-    instead of once per query head, preserving blocked_attention's 1/G KV
-    memory-traffic saving on the kernel path.
+    of a single KV head, stacked along the q-row axis — the KV stream is
+    read once per *group* instead of once per query head, preserving
+    blocked_attention's 1/G KV memory-traffic saving on the kernel path.
+    The q rows run in tiles of ``tq = q_tile(nb)`` positions, tile-major:
+    row j·G·tq + g·tq + i is head g at sequence position j·tq + i (with one
+    tile, row g·nb + i).  Each tile is a grid step of its own.
     """
     s, rows, hd = q.shape
+    nb = rows // groups
+    tq = q_tile(nb)
     t = k.shape[1]
     hd_v = v.shape[2]
     chunk = min(chunk, round_up(t, 8))                   # auto-shrink for short KV
@@ -94,16 +115,18 @@ def extend_attention_streams(q, k, v, *, t_real, chunk: int = 512,
     if t_pad != t:                                       # mask covers the pad
         k = pad_axis(k, 1, t_pad)
         v = pad_axis(v, 1, t_pad)
-    kern = functools.partial(_kernel, chunk=chunk, groups=groups)
+    kern = functools.partial(_kernel, chunk=chunk, groups=groups, nb=nb)
+    tile = groups * tq
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,                           # t_real rides in SMEM
-        grid=(s,),
+        grid=(s, nb // tq),
         in_specs=[
-            pl.BlockSpec((1, rows, hd), lambda i, t: (i, 0, 0)),
-            pl.BlockSpec((1, t_pad, hd), lambda i, t: (i, 0, 0)),
-            pl.BlockSpec((1, t_pad, hd_v), lambda i, t: (i, 0, 0)),
+            pl.BlockSpec((1, tile, hd), lambda i, j, t: (i, j, 0)),
+            # the same block for every q tile of a stream: fetched once
+            pl.BlockSpec((1, t_pad, hd), lambda i, j, t: (i, 0, 0)),
+            pl.BlockSpec((1, t_pad, hd_v), lambda i, j, t: (i, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, rows, hd_v), lambda i, t: (i, 0, 0)),
+        out_specs=pl.BlockSpec((1, tile, hd_v), lambda i, j, t: (i, j, 0)),
     )
     return pl.pallas_call(
         kern,

@@ -24,7 +24,7 @@ import jax.numpy as jnp
 
 from repro.kernels.common import use_interpret
 
-from .kernel import extend_attention_streams
+from .kernel import extend_attention_streams, q_tile
 
 
 def extend_attention(q, k, v, *, t_real=None, chunk: int = 512,
@@ -45,17 +45,20 @@ def extend_attention(q, k, v, *, t_real=None, chunk: int = 512,
     if t_real is None:
         t_real = t
     # one stream per (batch, KV head); q head h' = k·g + g' shares KV head
-    # k, so the G group heads stack along the stream's q-row axis
-    qs = q.transpose(0, 2, 1, 3).reshape(b, kv, g * nb, hd).reshape(
-        b * kv, g * nb, hd)
+    # k, so the G group heads stack along the stream's q-row axis, within
+    # each tile of tq positions (the kernel's tile-major row order)
+    tq = q_tile(nb)
+    n_t = nb // tq
+    qs = q.reshape(b, n_t, tq, kv, g, hd).transpose(0, 3, 1, 4, 2, 5).reshape(
+        b * kv, n_t * g * tq, hd)
     ks = k.transpose(0, 2, 1, 3).reshape(b * kv, t, hd)
     vs = v.transpose(0, 2, 1, 3).reshape(b * kv, t, hd_v)
     if interpret is None:
         interpret = use_interpret()
     out = extend_attention_streams(qs, ks, vs, t_real=t_real, chunk=chunk,
                                    groups=g, interpret=interpret)
-    return out.reshape(b, kv, g, nb, hd_v).reshape(
-        b, h, nb, hd_v).transpose(0, 2, 1, 3)
+    return out.reshape(b, kv, n_t, g, tq, hd_v).transpose(
+        0, 2, 4, 1, 3, 5).reshape(b, nb, h, hd_v)
 
 
 def extend_attention_mla(q_nope, q_rope, k_nope, k_rope, v, *, t_real=None,

@@ -518,6 +518,15 @@ class LM:
             0, n_chunks, body, (caches, snap0, logits0))
         return logits, caches, snap
 
+    def extend_groupable(self) -> bool:
+        """Whether ``prefill_extend`` gives each token the same output
+        however many tokens share its pass, so that consecutive chunks may
+        run as one longer pass.  Not for SSD mixers, whose stored chunks
+        need the running state at each chunk's own end, nor for MoE MLPs,
+        which drop tokens by a capacity computed per pass."""
+        return all(spec.mixer in ("attn", "mla") and spec.mlp != "moe"
+                   for period, _ in self.segments for spec in period)
+
     def _extend_layer(self, spec: LayerSpec, p, cache, x, positions, start):
         cfg = self.cfg
         h = rms_norm(x.astype(self.compute_dtype), p["ln1"], cfg.norm_eps)

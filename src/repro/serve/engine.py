@@ -30,6 +30,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import trace
 from repro.core.cost import CostModel, serve_cost_model  # noqa: F401  (re-export:
 # serve_cost_model moved to core.cost so the analytical planner and the
 # serving layer share one F/C vocabulary; importing it from here keeps
@@ -41,6 +42,14 @@ from repro.kernels.common import bucket_len
 from .kv_cache import (DEFAULT_DOC, SegmentStore, cache_len, chunk_segment,
                        insert_cache, pad_cache_to, slice_cache)
 
+#: Stored chunks one grouped prompt-build pass covers.  A pass streams
+#: every weight once whatever its length: deepseek-67b-4L's 3.608e9 bf16
+#: parameters (4 layers of 692.2M, the 838.9M head) are 7.22 GB, 8.81 ms at
+#: a v5e's 819 GB/s, against 5.54 GFLOP of matmuls a token at 197 TFLOP/s.
+#: A pass stops waiting on HBM past 8.81 ms × 197e12 / 5.54e9 ≈ 313 tokens,
+#: so 4 chunks of 128 (512 tokens) reach past that ridge.
+GROUP_CHUNKS = 4
+
 
 @dataclass
 class ServeStats:
@@ -48,6 +57,11 @@ class ServeStats:
     tokens_reused: int = 0
     tokens_computed: int = 0
     tokens_decoded: int = 0
+    #: weight passes over full stored chunks in prompt builds, and the
+    #: tokens they computed: ``GROUP_CHUNKS`` chunks a pass where the model
+    #: groups them, one where it does not
+    extend_passes: int = 0
+    extend_pass_tokens: int = 0
     planner_s: float = 0.0
     prefill_s: float = 0.0
     decode_s: float = 0.0
@@ -58,6 +72,11 @@ class ServeStats:
     def reuse_frac(self) -> float:
         tot = self.tokens_reused + self.tokens_computed
         return self.tokens_reused / tot if tot else 0.0
+
+    @property
+    def tokens_per_extend_pass(self) -> float:
+        return (self.extend_pass_tokens / self.extend_passes
+                if self.extend_passes else 0.0)
 
     @property
     def prefill_tok_s(self) -> float:
@@ -141,7 +160,9 @@ class PrefixCacheBuilder:
         # capacity and `start` is a traced operand, so the executables
         # below are compiled O(#buckets) times, not O(#chunks)
         self.lowerings = {"prefill": 0, "extend": 0, "extend_many": 0,
-                          "insert": 0}
+                          "extend_group": 0, "insert": 0}
+        #: chunks per gap-filling weight pass (``_fill_gap``)
+        self.group_chunks = GROUP_CHUNKS if model.extend_groupable() else 1
         #: segments dequantized on the reuse path (int8 residents whose
         #: payload was reconstructed before entering the jitted insert)
         self.dequants = 0
@@ -153,6 +174,8 @@ class PrefixCacheBuilder:
         self._jit_extend = jax.jit(self._counted(model.prefill_extend, "extend"))
         self._jit_extend_many = jax.jit(
             self._counted(model.prefill_extend_many, "extend_many"))
+        self._jit_extend_group = jax.jit(
+            self._counted(model.prefill_extend_many, "extend_group"))
         self._jit_insert = jax.jit(self._counted(insert_cache, "insert"))
 
     def _segment_caches(self, seg):
@@ -218,10 +241,11 @@ class PrefixCacheBuilder:
         ``bucket_len(max(length, capacity), seq_bucket)`` — the shape
         discipline that bounds compilation: every gap is filled through
         the shape-stable ``prefill_extend`` entry points at this bucketed
-        capacity, and a whole gap's worth of full chunks goes through one
-        ``prefill_extend_many`` dispatch (a jitted fori_loop over chunk
-        slots) instead of one host round-trip per chunk.  Each chunk is
-        still materialized for future requests (paper Alg 2 behaviour).
+        capacity, and a whole gap's worth of full chunks goes through at
+        most two ``prefill_extend_many`` dispatches (jitted fori_loops over
+        slots of grouped and of single chunks) instead of one host
+        round-trip per chunk.  Each chunk is still materialized for
+        future requests (paper Alg 2 behaviour).
         Segments the plan references are pinned for the duration so chunk
         puts can never evict them mid-execution.
 
@@ -338,8 +362,11 @@ class PrefixCacheBuilder:
                   stats, sink):
         """Prefill one uncovered plan step [rng.lo, rng.hi) into ``caches``.
 
-        Full chunks run as a single fused ``prefill_extend_many`` dispatch;
-        at most one ragged remainder runs as a single ``prefill_extend``.
+        Full chunks run in fused ``prefill_extend_many`` dispatches: first
+        every whole group of ``group_chunks`` chunks, one weight pass a
+        group, then the chunks left over, one pass each.  Groups are only
+        ever full, so no pass writes past ``hi``.  At most one ragged
+        remainder runs as a single ``prefill_extend``.
         Only a cold start at position 0 uses the exact-shape ``prefill``
         (one compile per distinct first-chunk length).  ``sink`` receives
         each chunk's materialized segment (None = don't materialize); the
@@ -365,19 +392,32 @@ class PrefixCacheBuilder:
         cur = cache_len(caches)
         assert cur == 0 or cur >= hi, f"cache capacity {cur} < gap end {hi}"
         n_full = (hi - lo) // self.chunk
+        g = self.group_chunks
+        n_grouped = n_full // g * g if g > 1 else 0
+        if n_grouped:
+            caches, _ = self._extend_passes(
+                self._jit_extend_group, doc, lo, n_grouped,
+                self.chunk * g, caches, cap)
+        if n_full > n_grouped:
+            caches, states = self._extend_passes(
+                self._jit_extend_many, doc, lo + n_grouped * self.chunk,
+                n_full - n_grouped, self.chunk, caches, cap)
         if n_full:
-            n_slots = cap // self.chunk          # static per (cap, chunk)
-            toks = np.zeros((1, n_slots, self.chunk), np.int32)
-            toks[0, :n_full] = np.asarray(
-                doc[lo:lo + n_full * self.chunk]).reshape(n_full, self.chunk)
-            _, caches, states = self._jit_extend_many(
-                self.params, caches, jnp.asarray(toks),
-                jnp.asarray(lo, jnp.int32), jnp.asarray(n_full, jnp.int32))
             if sink is not None:
+                # stored segments stay one chunk each; grouped stacks keep
+                # no running state, so their chunks are plain slices
                 for i in range(n_full):
                     a = lo + i * self.chunk
                     sink(Range(a, a + self.chunk),
-                         chunk_segment(caches, states, i, a, a + self.chunk))
+                         slice_cache(caches, a, a + self.chunk)
+                         if i < n_grouped else
+                         chunk_segment(caches, states, i - n_grouped, a,
+                                       a + self.chunk))
+            passes = n_grouped // g + n_full - n_grouped
+            stats.extend_passes += passes
+            stats.extend_pass_tokens += n_full * self.chunk
+            trace.count("repro.serve.extend_pass", passes)
+            trace.count("repro.serve.extend_pass_tokens", n_full * self.chunk)
             stats.tokens_computed += n_full * self.chunk
             lo += n_full * self.chunk
         if lo < hi:                              # ragged remainder chunk
@@ -388,6 +428,21 @@ class PrefixCacheBuilder:
                 sink(Range(lo, hi), slice_cache(caches, lo, hi))
             stats.tokens_computed += hi - lo
         return caches
+
+    def _extend_passes(self, jitted, doc, lo: int, n_chunks: int,
+                       width: int, caches, cap: int):
+        """Extend ``caches`` with ``doc[lo : lo + n_chunks·chunk]`` in one
+        dispatch of passes ``width`` tokens long (a chunk multiple).  The
+        token buffer has a slot for every pass ``cap`` could hold, so the
+        executable depends only on (cap, width).  Returns (caches, the
+        per-pass state snapshots)."""
+        n = n_chunks * self.chunk // width
+        toks = np.zeros((1, cap // width, width), np.int32)
+        toks[0, :n] = np.asarray(doc[lo:lo + n * width]).reshape(n, width)
+        _, caches, states = jitted(
+            self.params, caches, jnp.asarray(toks),
+            jnp.asarray(lo, jnp.int32), jnp.asarray(n, jnp.int32))
+        return caches, states
 
     def prefix_with_logits(self, doc: np.ndarray, prefix_len: int, *,
                            doc_id: str = DEFAULT_DOC,

@@ -29,7 +29,7 @@ a follow-up request over generated context plans entirely from the store
 
 Pipelined serving (PR 5): the loop is an explicit three-stage pipeline —
 **admit → prefill → decode**.  ``submit`` (admit) plans the prefix and
-*launches* the build (one async ``prefill_extend_many`` dispatch per plan
+*launches* the build (async ``prefill_extend_many`` dispatches per plan
 gap — JAX async dispatch means nothing blocks the host), parking the
 session behind a :class:`PrefillTicket`.  The scheduler keeps batching
 already-warm sessions while tickets are in flight; a ticketed session
@@ -901,6 +901,12 @@ class SessionManager:
             "tokens_computed": agg.tokens_computed,
             "reuse_frac": agg.reuse_frac,
             "prefill_tok_s": agg.prefill_tok_s,
+            # prompt-build weight passes over full stored chunks, their
+            # tokens, and the tokens a pass computes on average: several
+            # chunks where the model groups them, one where it does not
+            "extend_passes": agg.extend_passes,
+            "extend_pass_tokens": agg.extend_pass_tokens,
+            "tokens_per_extend_pass": agg.tokens_per_extend_pass,
             "decode_tok_s": agg.decode_tok_s,
             "decode_calls": sc.decode_calls,
             "mean_batch": sc.mean_batch,
@@ -988,5 +994,7 @@ def _accumulate(into: ServeStats, src: ServeStats) -> None:
     into.tokens_reused += src.tokens_reused
     into.tokens_computed += src.tokens_computed
     into.tokens_decoded += src.tokens_decoded
+    into.extend_passes += src.extend_passes
+    into.extend_pass_tokens += src.extend_pass_tokens
     into.planner_s += src.planner_s
     into.prefill_s += src.prefill_s

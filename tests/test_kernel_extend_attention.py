@@ -150,3 +150,22 @@ def test_matches_fresh_prefill_semantics():
     out = ops.extend_attention(q_all[:, -nb:], k, v, chunk=16)
     np.testing.assert_allclose(np.asarray(out), np.asarray(full[:, -nb:]),
                                rtol=1e-4, atol=1e-5)
+
+
+def test_q_tiles_at_serving_widths():
+    """A 512-position extend runs as four q tiles of 128 positions, each
+    taking its causal offset from its tile index: 8 query heads over one KV
+    head of 128, a 3840-position cache whose valid length is off every
+    chunk and tile boundary, against the oracle."""
+    from repro.kernels.extend_attention.kernel import q_tile
+
+    b, nb, g, hd, cap, t_real = 1, 512, 8, 128, 3840, 2701
+    assert q_tile(nb) == 128 and t_real % 128
+    q = _rand((b, nb, g, hd), np.float32, 50)
+    k = _rand((b, cap, 1, hd), np.float32, 51)
+    v = _rand((b, cap, 1, hd), np.float32, 52)
+    out = ops.extend_attention(q, k, v, t_real=t_real, interpret=True)
+    ref = extend_attention_ref(jnp.asarray(q), jnp.repeat(jnp.asarray(k), g, 2),
+                               jnp.repeat(jnp.asarray(v), g, 2), t_real=t_real)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=1e-4, atol=1e-5)

@@ -7,7 +7,9 @@ prefill paid N distinct XLA lowerings — the incremental step cost more in
 compiles than recomputation cost in FLOPs (the exact inversion of the
 paper's Alg 2 economics).  These tests pin the fix: one shape-stable
 executable per (cache bucket, chunk shape), counted via the builder's
-trace-counting wrappers.
+trace-counting wrappers.  Dense attention stacks fill a gap's whole
+groups of four chunks through one more executable per cache bucket (the
+grouped weight pass), counted apart as ``extend_group``.
 """
 import jax
 import numpy as np
@@ -44,12 +46,14 @@ def test_cold_multidoc_serve_lowerings_bounded_by_buckets(setup):
     agg = mgr.aggregate_stats()
     chunks = agg.tokens_computed // 32
     low = mgr.builder.lowerings
-    # per cold doc: prefill [0,32), one fused extend_many for [32,224),
-    # one ragged remainder [224,255), one 1-token boundary extend — all
-    # four executables are shared across the three documents
+    # per cold doc: prefill [0,32), one grouped pass for [32,160), one
+    # fused extend_many for [160,224), one ragged remainder [224,254), one
+    # 1-token boundary extend — all five executables are shared across the
+    # three documents
     assert chunks >= 3 * 8, f"expected ≥24 chunks of work, got {chunks}"
     assert low["extend_many"] == 1, low
-    assert mgr.builder.extend_lowerings <= 5, (
+    assert low == {"prefill": 1, "extend": 2, "extend_many": 1,
+                   "extend_group": 1, "insert": 0}, (
         f"cold prefill must compile O(#buckets) executables, "
         f"got {low} for {chunks} chunks")
 
@@ -68,6 +72,7 @@ def test_new_length_same_bucket_adds_no_gap_loop_compile(setup):
     mgr.run()
     after = mgr.builder.lowerings
     assert after["extend_many"] == before["extend_many"], (before, after)
+    assert after["extend_group"] == before["extend_group"] == 1, (before, after)
     assert after["prefill"] == before["prefill"], (before, after)
 
 
@@ -91,15 +96,38 @@ def test_multi_gap_plan_single_dispatch_per_gap(setup):
     assert len(gaps) >= 2, "plan should interleave reuse and gaps"
     low = eng.builder.lowerings
     # gaps [0,64), [96,160), [192,256): one prefill + one shared fused
-    # loop; the two 32-token segment inserts share one executable
+    # loop (no gap holds a group of four chunks); the two 32-token segment
+    # inserts share one executable
     assert low["extend_many"] == 1, low
     assert low["insert"] <= 1, low
-    assert eng.builder.extend_lowerings <= 4, low
+    assert low == {"prefill": 1, "extend": 0, "extend_many": 1,
+                   "extend_group": 0, "insert": 1}, low
     assert cache_len(caches) == cache_len(ref_caches)
     np.testing.assert_allclose(
         np.asarray(caches[0]["p0"]["k"][:, :, :256]),
         np.asarray(ref_caches[0]["p0"]["k"][:, :, :256]),
         rtol=1e-5, atol=1e-5)
+
+
+def test_grouped_pass_compiles_once_per_capacity(setup):
+    """The grouped weight pass is one executable per cache capacity: cold
+    documents whose gaps hold a group at two capacities build two, and a
+    third at one of them builds none."""
+    cfg, model, params, docs = setup
+    mgr = SessionManager(model, params, chunk_tokens=32, decode_bucket=64)
+    # prefix 256 (+2) rides at 320; prefix 192 (+2) and 200 (+2) at 256;
+    # each gap after the cold first chunk holds 4-6 full chunks
+    for doc, prefix in zip(docs[:3], (256, 192, 200)):
+        mgr.submit(mgr.add_session(doc), prefix, 2)
+        mgr.run()
+        if prefix == 192:
+            assert mgr.builder.lowerings["extend_group"] == 2
+    low = mgr.builder.lowerings
+    assert low["extend_group"] == 2, low
+    rep = mgr.report()
+    # passes: a group and 2 chunks; a group; a group and 1 chunk
+    assert rep["extend_passes"] == 3 + 1 + 2, rep
+    assert rep["extend_pass_tokens"] == (6 + 4 + 5) * 32, rep
 
 
 def test_ragged_segment_hits_compile_per_bucket_not_per_length(setup):

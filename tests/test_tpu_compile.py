@@ -108,6 +108,11 @@ CASES = {
     "extend_attention": (_extend, ((1, 128, KV * G, HD), BF16),
                          ((1, T, KV, HD), BF16), ((1, T, KV, HD), BF16),
                          ((), I32)),
+    # one sequence, a 512-token grouped pass (four q tiles of 128) at the
+    # end of a 3840-position serving slot: VMEM as at 128 tokens
+    "extend_attention_512": (_extend, ((1, 512, KV * G, HD), BF16),
+                             ((1, 3840, KV, HD), BF16),
+                             ((1, 3840, KV, HD), BF16), ((), I32)),
     # the paper's 5M rows, [X | y] padded to one lane tile
     "linreg_zt_z": (_zt_z, ((5_000_192, 128), F32)),
     "nb_grouped_stats": (_grouped_stats, ((1_000_448, 128), F32),
